@@ -2,7 +2,9 @@
 latency vs the number of concurrent sensors (CPU wall-times; the batched
 readout is one kernel call whatever the sensor count), plus the
 device-parallel sweep: the same pool sharded over 1/2/4/8 emulated host
-devices (subprocess, so the main process stays single-device), plus the
+devices (a subprocess pinned to the CPU platform, so it never contends
+for an accelerator the main process holds; its rows are named
+``serve_sharded_cpuemu_*`` for that reason), plus the
 fused-vs-unfused ingest+read loop (below), plus the composed-ReadoutSpec
 row: ``surface + stcf + count`` served from one fused dispatch vs three
 sequential single-product reads (``serve_spec_*``), gated bitwise so the
@@ -59,9 +61,9 @@ COMPOSED = rs.ReadoutSpec(surface=rs.surface(), stcf=rs.stcf(),
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# Runs under 8 emulated host devices; prints one CSV row per measurement.
-# The unsharded engine built in the same process is the bit-identical
-# oracle for every device count.
+# Runs under 8 emulated host devices on the CPU platform; prints one CSV
+# row per measurement.  The unsharded engine built in the same process is
+# the bit-identical oracle for every device count.
 _SHARDED_SWEEP = """
 import os
 os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'
@@ -120,22 +122,24 @@ for nd in (1, 2, 4, 8):
     sup = np.asarray(eng.read(STCF, DURATION)['stcf'])
     assert (sup[:N] == want_sup).all(), f'sharded support != unsharded (nd={{nd}})'
 
-    print(f'serve_sharded_ingest_{{nd}}dev_us,'
+    print(f'serve_sharded_cpuemu_ingest_{{nd}}dev_us,'
           f'{{dt_ingest * 1e6:.1f}},{{n_events / dt_ingest / 1e6:.4f}}')
-    print(f'serve_sharded_readout_{{nd}}dev_us,'
+    print(f'serve_sharded_cpuemu_readout_{{nd}}dev_us,'
           f'{{dt_read * 1e6:.1f}},{{N * H * W / dt_read / 1e6:.4f}}')
 """
 
 
 def sharded_rows(h=H, w=W, duration=DURATION):
     """1/2/4/8-device sweep rows from the subprocess (bit-identical gate
-    runs inside it; a non-zero exit surfaces as a harness ERROR row)."""
+    runs inside it; a non-zero exit surfaces as a harness ERROR row).
+    The child is pinned to ``JAX_PLATFORMS=cpu``: a chip belongs to one
+    process, and this parent already holds it on an accelerator host."""
     script = textwrap.dedent(
         _SHARDED_SWEEP.format(h=h, w=w, duration=duration)
     )
     src = os.path.join(_REPO, "src")
     inherited = os.environ.get("PYTHONPATH")
-    env = dict(os.environ, PYTHONPATH=(
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=(
         src + os.pathsep + inherited if inherited else src
     ))
     out = subprocess.run([sys.executable, "-c", script], capture_output=True,
@@ -146,7 +150,7 @@ def sharded_rows(h=H, w=W, duration=DURATION):
     )
     rows_ = []
     for line in out.stdout.splitlines():
-        if line.startswith("serve_sharded_"):
+        if line.startswith("serve_sharded_cpuemu_"):
             name, us, derived = line.split(",")
             rows_.append((name, float(us), float(derived)))
     assert len(rows_) == 8, out.stdout

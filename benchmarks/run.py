@@ -145,6 +145,7 @@ def main() -> None:
     from repro import platform as pf
 
     pf.set_platform(args.platform)
+    pf.use_compile_cache()
     desc = pf.describe()
     print(f"# platform: {desc}", file=sys.stderr)
     platform = platform_meta(desc)

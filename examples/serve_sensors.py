@@ -37,15 +37,17 @@ def window(s, lo: float, hi: float) -> np.ndarray:
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--mesh", type=int, default=0, metavar="N",
-                    help="shard the slot pool over N emulated host devices")
+                    help="shard the slot pool over N devices (TPU chips, "
+                         "or emulated host devices on CPU)")
     args = ap.parse_args()
 
     # mesh setup must precede any jax device use (host-device emulation)
     mesh = None
     if args.mesh:
+        from repro import platform as pf
         from repro.launch import mesh as mesh_mod
 
-        mesh_mod.ensure_host_device_count(args.mesh)
+        pf.request_mesh_devices(args.mesh)
         mesh = mesh_mod.make_host_mesh(args.mesh)
 
     from repro.events import datasets

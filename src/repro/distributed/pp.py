@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 
 def bubble(n_stages: int, n_micro: int) -> float:
     return (n_stages - 1) / (n_micro + n_stages - 1)
@@ -75,10 +73,10 @@ def pipeline_forward(
         # only the last stage wrote anything; psum makes it replicated
         return jax.lax.psum(outputs, stage_axis)
 
-    out = compat.shard_map(
+    out = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(stage_axis), P()),
         out_specs=P(),
-        check=False,
+        check_vma=False,
     )(params_stacked, x)
     return out

@@ -39,10 +39,16 @@ def _decay_kernel(bt, a_ref, x_ref, out_ref, final_ref, s_ref):
     a = a_ref[0]          # (bt, bc)
     x = x_ref[0]
 
-    # inclusive associative scan along the chunk (axis 0)
-    aa, xx = jax.lax.associative_scan(
-        lambda l, r: (l[0] * r[0], l[1] * r[0] + r[1]), (a, x), axis=0
-    )
+    # inclusive Hillis-Steele scan along the chunk (axis 0): shifted
+    # static slices, which Mosaic lowers (lax.associative_scan's strided
+    # slices it does not)
+    aa, xx = a, x
+    d = 1
+    while d < bt:
+        a_prev = jnp.concatenate([jnp.ones_like(aa[:d]), aa[:-d]], axis=0)
+        x_prev = jnp.concatenate([jnp.zeros_like(xx[:d]), xx[:-d]], axis=0)
+        aa, xx = _combine(a_prev, x_prev, aa, xx)
+        d *= 2
     s0 = s_ref[...]       # (1, bc) running state entering this chunk
     states = aa * s0 + xx                 # (bt, bc)
     out_ref[0] = states.astype(out_ref.dtype)
@@ -50,7 +56,7 @@ def _decay_kernel(bt, a_ref, x_ref, out_ref, final_ref, s_ref):
 
     @pl.when(t_idx == pl.num_programs(2) - 1)
     def _fin():
-        final_ref[0] = states[-1].astype(final_ref.dtype)
+        final_ref[0] = states[-1:].astype(final_ref.dtype)
 
 
 def decay_scan_pallas(
@@ -84,13 +90,16 @@ def decay_scan_pallas(
         functools.partial(_decay_kernel, bt),
         grid=grid,
         in_specs=[blk, blk],
-        out_specs=[blk, pl.BlockSpec((1, bc), lambda bi, ci, ti: (bi, ci))],
+        # final state as (B, 1, C): a (1, 1, bc) block keeps its last two
+        # dims (full 1, lane-aligned bc) within the TPU's block rule
+        out_specs=[blk, pl.BlockSpec((1, 1, bc),
+                                     lambda bi, ci, ti: (bi, 0, ci))],
         out_shape=[
             jax.ShapeDtypeStruct((b, tp, c + pc), jnp.float32),
-            jax.ShapeDtypeStruct((b, c + pc), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, c + pc), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, bc), jnp.float32)],
         interpret=interpret,
     )(a_p, x_p)
     lead = bt if s0 is not None else 0
-    return out[:, lead : lead + t, :c], final[:, :c]
+    return out[:, lead : lead + t, :c], final[:, 0, :c]

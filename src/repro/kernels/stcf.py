@@ -35,14 +35,13 @@ def _support_kernel(r, include_self, fused, prev_ref, cur_ref,
         v = a1 * jnp.exp(-dt / tau1) + a2 * jnp.exp(-dt / tau2) + b
         rows = jnp.where(jnp.isfinite(rows), v, 0.0)
         rows = (rows > v_tw).astype(jnp.float32)
-    acc = jnp.zeros((bh, wpad - 2 * r), jnp.float32)
+    w = wpad - 2 * r
+    acc = jnp.zeros((bh, w), jnp.float32)
     for dy in range(-r, r + 1):
-        band = jax.lax.dynamic_slice_in_dim(rows, bh + dy, bh, axis=0)
+        band = rows[bh + dy:2 * bh + dy]
         for dx in range(-r, r + 1):
             if include_self or not (dy == 0 and dx == 0):
-                acc = acc + jax.lax.dynamic_slice_in_dim(
-                    band, r + dx, wpad - 2 * r, axis=1
-                )
+                acc = acc + band[:, r + dx:r + dx + w]
     out_ref[...] = acc.astype(out_ref.dtype)
 
 

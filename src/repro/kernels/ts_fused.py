@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEVER_SENTINEL = -jnp.inf
 
@@ -48,12 +49,10 @@ def _scatter_kernel(n_events, sae_ref, ex_ref, ey_ref, et_ref, new_ref):
     y0 = pl.program_id(0) * bh
     rows = y0 + lax.broadcasted_iota(jnp.int32, (bh, wp), 0)
     cols = lax.broadcasted_iota(jnp.int32, (bh, wp), 1)
-    ex, ey, et = ex_ref[...], ey_ref[...], et_ref[...]   # (1, N) each
 
     def body(k, acc):
-        gx = lax.dynamic_slice(ex, (0, k), (1, 1))[0, 0]
-        gy = lax.dynamic_slice(ey, (0, k), (1, 1))[0, 0]
-        tv = lax.dynamic_slice(et, (0, k), (1, 1))[0, 0]
+        # one event per step, read as scalars out of SMEM
+        gx, gy, tv = ex_ref[0, k], ey_ref[0, k], et_ref[0, k]
         hit = (rows == gy) & (cols == gx)
         return jnp.where(hit, jnp.maximum(acc, tv), acc)
 
@@ -77,7 +76,10 @@ def chunk_scatter_pallas(
     n = ex.shape[0]
 
     tile = pl.BlockSpec((bh, wp), lambda i: (i, 0))
-    ev_spec = pl.BlockSpec((1, n), lambda i: (0, 0))
+    # the whole (1, N) chunk in SMEM; (1, N) rather than (N,) so a vmapped
+    # call's (1, 1, N) block keeps its last two dims equal to the array's
+    ev_spec = pl.BlockSpec((1, n), lambda i: (0, 0),
+                           memory_space=pltpu.SMEM)
     new = pl.pallas_call(
         functools.partial(_scatter_kernel, n),
         grid=(rp // bh,),

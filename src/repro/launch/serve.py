@@ -97,7 +97,7 @@ def run_sensors(args) -> None:
     if args.mesh:
         # must precede any jax device use (TSEngineConfig resolves the
         # backend) so XLA still honors the host-device-count flag on CPU
-        pf.ensure_host_device_count(args.mesh)
+        pf.request_mesh_devices(args.mesh)
         mesh = mesh_mod.make_host_mesh(args.mesh)
         print(f"mesh: {dict(mesh.shape)} over "
               f"{[d.platform for d in mesh.devices.ravel()][0]} devices")
@@ -205,7 +205,7 @@ def run_stream(args) -> None:
         ) from None
     mesh = None
     if args.mesh:
-        pf.ensure_host_device_count(args.mesh)
+        pf.request_mesh_devices(args.mesh)
         mesh = mesh_mod.make_host_mesh(args.mesh)
         print(f"mesh: {dict(mesh.shape)}")
 
@@ -602,6 +602,7 @@ def main() -> None:
     # platform config must precede the first jax device use (every
     # subcommand resolves a backend or touches devices early)
     pf.set_platform(args.platform)
+    pf.use_compile_cache()
     if args.x64:
         pf.enable_x64(True)
     if args.engine == "tokens":

@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.models.module import ParamDef
 
@@ -179,7 +178,7 @@ def moe_sharded(
     def fn(router_w, wg, wu, wd, x_loc):
         # barrier at the manual level: stops XLA:CPU hoisting the bf16->f32
         # dot-input converts out of the layer loop as full-stack f32 copies
-        wg, wu, wd = compat.optimization_barrier((wg, wu, wd))
+        wg, wu, wd = jax.lax.optimization_barrier((wg, wu, wd))
         xf = x_loc.reshape(-1, d)
         if fsdp_axis is not None:
             wg = jax.lax.all_gather(wg, fsdp_axis, axis=1, tiled=True)
@@ -198,11 +197,11 @@ def moe_sharded(
         )
         return y.reshape(x_loc.shape).astype(x_loc.dtype), aux
 
-    shard = compat.shard_map(
+    shard = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(None, None), up_spec, up_spec, down_spec, x_spec),
         out_specs=(x_spec, {"lb_loss": P(), "z_loss": P()}),
-        check=False,
+        check_vma=False,
     )
     y, aux = shard(params["router"], params["we_gate"], params["we_up"],
                    params["we_down"], x)

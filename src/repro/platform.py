@@ -2,18 +2,21 @@
 
 One module owns every knob that must be set **before** the jax backend
 initializes — platform selection, GPU XLA performance flags, emulated
-host-device counts, x64/debug toggles — so launchers, benchmarks, and CI
-stop growing their own ``os.environ`` handling (the pattern follows
-bayespec's ``elisa/util/config.py``).  Everything here is idempotent and
-safe to call repeatedly; the functions that *must* precede backend
-initialization say so and fail loudly when called too late.
+host-device counts, the persistent compilation cache, x64/debug toggles
+— so launchers, benchmarks, and CI stop growing their own
+``os.environ`` handling (the pattern follows bayespec's
+``elisa/util/config.py``).  Everything here is idempotent and safe to
+call repeatedly; the functions that *must* precede backend
+initialization say so and fail loudly when called too late.  Nothing
+here runs at import.
 
 Typical launcher preamble::
 
     from repro import platform as pf
 
     pf.set_platform(args.platform)        # 'cpu' | 'gpu' | 'tpu' | None
-    pf.set_host_device_count(args.mesh)   # CPU multi-device emulation
+    pf.use_compile_cache()                # persistent compile cache
+    pf.request_mesh_devices(args.mesh)    # CPU: emulated host devices
     ...first jax device use happens after...
 
 The GPU flags mirror the latency-oriented serving profile: the
@@ -26,6 +29,7 @@ serializing against any host-driven transfer.
 from __future__ import annotations
 
 import os
+import pathlib
 import re
 from typing import Dict, Optional
 
@@ -34,7 +38,9 @@ import jax
 __all__ = [
     "PLATFORMS", "GPU_XLA_FLAGS", "HOST_DEVICE_COUNT_FLAG",
     "merge_xla_flags", "set_platform", "enable_x64", "debug_nans",
-    "set_host_device_count", "ensure_host_device_count", "describe",
+    "set_host_device_count", "ensure_host_device_count",
+    "requested_platform", "request_mesh_devices", "COMPILE_CACHE_DIR",
+    "use_compile_cache", "describe",
 ]
 
 #: the platforms ``set_platform`` accepts (None = let jax pick)
@@ -54,6 +60,13 @@ GPU_XLA_FLAGS = (
 
 #: the emulated host-device-count flag (CPU multi-device testing)
 HOST_DEVICE_COUNT_FLAG = "--xla_force_host_platform_device_count"
+
+#: the persistent compilation cache's directory when
+#: ``JAX_COMPILATION_CACHE_DIR`` is unset: a fixed path inside the
+#: checkout (the directory is part of the cache key, so a path that moved
+#: between runs would never hit)
+COMPILE_CACHE_DIR = str(pathlib.Path(__file__).resolve().parents[2]
+                        / ".jax_cache")
 
 
 def merge_xla_flags(new_flags, env: Optional[Dict[str, str]] = None) -> str:
@@ -157,6 +170,46 @@ def ensure_host_device_count(n: int) -> None:
             f"XLA_FLAGS={HOST_DEVICE_COUNT_FLAG}={n} before any jax "
             "device use"
         )
+
+
+def requested_platform() -> Optional[str]:
+    """The platform this process asked jax for, read without
+    initializing the backend: the first entry of ``jax_platforms``
+    (``JAX_PLATFORMS``), else ``jax_platform_name`` (``set_platform``);
+    ``None`` when jax is left to pick."""
+    platforms = jax.config.jax_platforms or ""
+    first = platforms.split(",")[0].strip()
+    return first or jax.config.read("jax_platform_name") or None
+
+
+def request_mesh_devices(n: int) -> None:
+    """Prepare ``n`` devices for an ``n``-way mesh; call before the first
+    jax device use.
+
+    Host-device emulation is the CPU platform's way to have ``n``
+    devices, so the flag is written only when the process asked for the
+    CPU or left the choice to jax (the flag shapes the CPU client alone,
+    and is inert when jax then picks an accelerator).  On a TPU the mesh
+    takes the real chips; ``launch.mesh.make_host_mesh`` fails, naming
+    chips, when the host has fewer than ``n``.
+    """
+    if requested_platform() in (None, "cpu"):
+        set_host_device_count(n)
+
+
+def use_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache; returns its directory.
+
+    When ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and
+    nothing is set here.  Otherwise the cache goes to the fixed
+    :data:`COMPILE_CACHE_DIR` (never a temporary, PID- or time-based
+    path).  Call from an entry point's ``main``, never at import.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
 def describe() -> Dict[str, object]:

@@ -81,7 +81,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.core import edram
 from repro.core import representations as representations_mod
 from repro.core import stcf as stcf_mod
@@ -673,14 +672,14 @@ def apply_heads(
             from repro.models.frontends import ts_stack_frontend
 
             stack = ts_stack_frontend(
-                [compat.optimization_barrier(stage0_out[n])
+                [jax.lax.optimization_barrier(stage0_out[n])
                  for n in h.inputs]
             )
             out[name] = cnn.cnn_apply(head_params[name], stack)
         elif isinstance(h, Denoise):
             thr = (h.threshold if h.threshold is not None
                    else cfg.stcf_threshold)
-            sup = compat.optimization_barrier(stage0_out[h.input])
+            sup = jax.lax.optimization_barrier(stage0_out[h.input])
             out[name] = sup >= thr
         else:  # pragma: no cover — closed by the constructor type check
             raise TypeError(h)

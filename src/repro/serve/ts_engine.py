@@ -116,8 +116,6 @@ from jax import lax
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
 from repro.core import edram
 from repro.core import stcf as stcf_mod
 from repro.core import time_surface as ts
@@ -581,8 +579,8 @@ class _ShardPlan:
         backend = ops.resolve_backend(cfg.backend)
 
         def smap(fn, in_specs, out_specs):
-            return compat.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                    out_specs=out_specs, check=False)
+            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False)
 
         def local_ingest(state, slot_ids, ev):
             # slot_ids are *local* (host routing already picked the shard)
@@ -1829,6 +1827,7 @@ class TimeSurfaceEngine:
     def stats(self) -> dict:
         s, n = self.state, self.capacity
         out = {
+            "backend": self._backend,
             "capacity": self.capacity,
             "n_slots_padded": self.n_slots_padded,
             "slot_bucket": self.slot_bucket,

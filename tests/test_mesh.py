@@ -1,24 +1,18 @@
-"""Mesh-construction unit tests: the jax-0.4.37 AxisType feature gate,
+"""Mesh-construction unit tests: Auto axis types on every mesh builder,
 host-device helpers, and slot-pool placement rules.
 
-``jax.sharding.AxisType`` only exists on jax >= 0.5; ``launch.mesh`` must
-build meshes on either side of that line.  Both sides are exercised here
-by monkeypatching the availability, with ``jax.make_mesh`` replaced by a
-recorder so no real >1-device mesh is needed in the fast gate (the real
-8/512-device builds run in the slow subprocess tests).
+``jax.make_mesh`` is replaced by a recorder where a mesh bigger than the
+test process's devices is asked for, so no real >1-device mesh is needed
+in the fast gate (the real 8/512-device builds run in the slow
+subprocess tests).
 """
-import enum
 import os
 
 import jax
 import pytest
 
+from repro import platform as pf
 from repro.launch import mesh as mesh_mod
-
-
-class _FakeAxisType(enum.Enum):
-    Auto = "auto"
-    Explicit = "explicit"
 
 
 class _Recorder:
@@ -31,49 +25,29 @@ class _Recorder:
 
 
 # ----------------------------------------------------------------------------
-# axis-type availability matrix
+# axis types
 # ----------------------------------------------------------------------------
 
-def test_axis_types_kwargs_absent(monkeypatch):
-    monkeypatch.delattr(jax.sharding, "AxisType", raising=False)
-    assert mesh_mod._axis_types_kwargs(2) == {}
-
-
-def test_axis_types_kwargs_present(monkeypatch):
-    monkeypatch.setattr(jax.sharding, "AxisType", _FakeAxisType,
-                        raising=False)
-    kw = mesh_mod._axis_types_kwargs(3)
-    assert kw == {"axis_types": (_FakeAxisType.Auto,) * 3}
-
-
-@pytest.mark.parametrize("with_axis_type", [False, True])
-def test_make_meshes_across_axis_type_availability(monkeypatch,
-                                                   with_axis_type):
+def test_make_meshes_declare_auto_axes(monkeypatch):
     rec = _Recorder()
     monkeypatch.setattr(jax, "make_mesh", rec)
-    if with_axis_type:
-        monkeypatch.setattr(jax.sharding, "AxisType", _FakeAxisType,
-                            raising=False)
-    else:
-        monkeypatch.delattr(jax.sharding, "AxisType", raising=False)
 
     mesh_mod.make_production_mesh()
     mesh_mod.make_production_mesh(multi_pod=True)
     mesh_mod.make_test_mesh((2, 4))
-    (s1, a1, kw1), (s2, a2, kw2), (s3, a3, kw3) = rec.calls
+    mesh_mod.make_host_mesh(1)
+    (s1, a1, kw1), (s2, a2, kw2), (s3, a3, kw3), (s4, a4, kw4) = rec.calls
     assert (s1, a1) == ((16, 16), ("data", "model"))
     assert (s2, a2) == ((2, 16, 16), ("pod", "data", "model"))
     assert (s3, a3) == ((2, 4), ("data", "model"))
-    for axes, kw in ((a1, kw1), (a2, kw2), (a3, kw3)):
-        if with_axis_type:
-            assert kw["axis_types"] == (_FakeAxisType.Auto,) * len(axes)
-        else:
-            assert "axis_types" not in kw
+    assert (s4, a4) == ((1,), ("data",))
+    for axes, kw in ((a1, kw1), (a2, kw2), (a3, kw3), (a4, kw4)):
+        assert kw["axis_types"] == (jax.sharding.AxisType.Auto,) * len(axes)
 
 
 def test_make_test_mesh_builds_on_pinned_jax():
-    """The actual pinned-jax call path (regression for the 0.4.37 break);
-    single-device shape so the fast gate needs no XLA flags."""
+    """The real ``jax.make_mesh`` call path; single-device shape so the
+    fast gate needs no XLA flags."""
     m = mesh_mod.make_test_mesh((1, 1))
     assert dict(m.shape) == {"data": 1, "model": 1}
 
@@ -92,17 +66,25 @@ def test_make_host_mesh_too_many_devices_raises():
         mesh_mod.make_host_mesh(len(jax.devices()) + 1)
 
 
+def test_make_host_mesh_too_many_chips_names_the_chips():
+    class _Chip:
+        platform, device_kind = "tpu", "TPU v5 lite"
+
+    with pytest.raises(RuntimeError,
+                       match=r"2-chip mesh but this host has 1 tpu chip"):
+        mesh_mod.make_host_mesh(2, devices=[_Chip()])
+
+
 def test_ensure_host_device_count(monkeypatch):
     monkeypatch.setenv("XLA_FLAGS", "")
     n = len(jax.devices())
-    mesh_mod.ensure_host_device_count(n)   # satisfiable: no raise
-    # env handling lives in repro.platform now; the flag still lands in
-    # the process XLA_FLAGS through the mesh-facing alias
+    pf.ensure_host_device_count(n)   # satisfiable: no raise
+    # the flag lands in the process XLA_FLAGS
     assert f"--xla_force_host_platform_device_count={n}" in \
         os.environ["XLA_FLAGS"]
     monkeypatch.setenv("XLA_FLAGS", "")
     with pytest.raises(RuntimeError, match="already initialized"):
-        mesh_mod.ensure_host_device_count(n + 1)
+        pf.ensure_host_device_count(n + 1)
 
 
 # ----------------------------------------------------------------------------

@@ -5,6 +5,8 @@ Everything here runs against plain dict environments — never the real
 of the module is that these knobs only matter *before* backend
 initialization, which the test process has long passed.
 """
+import os
+
 import jax
 import pytest
 
@@ -110,6 +112,56 @@ def test_ensure_host_device_count_raises_after_backend_init():
         pf.ensure_host_device_count(n)
     # a satisfiable request is fine after init
     pf.ensure_host_device_count(1)
+
+
+def test_request_mesh_devices_emulates_only_for_the_cpu(monkeypatch):
+    """Host-device emulation is written for a CPU (or undecided)
+    platform only; a process that asked for the TPU takes its chips."""
+    monkeypatch.setenv("XLA_FLAGS", "")
+    monkeypatch.setattr(pf, "requested_platform", lambda: "tpu")
+    pf.request_mesh_devices(4)
+    assert os.environ["XLA_FLAGS"] == ""
+    for asked in ("cpu", None):
+        monkeypatch.setattr(pf, "requested_platform", lambda: asked)
+        pf.request_mesh_devices(4)
+        assert os.environ["XLA_FLAGS"] == f"{pf.HOST_DEVICE_COUNT_FLAG}=4"
+
+
+@pytest.mark.parametrize("platforms,name,want", [
+    ("tpu,cpu", "", "tpu"), ("cpu", "", "cpu"), ("", "gpu", "gpu"),
+    ("", "", None),
+])
+def test_requested_platform_reads_config_without_backend(
+        monkeypatch, platforms, name, want):
+    monkeypatch.setattr(type(jax.config), "jax_platforms",
+                        property(lambda self: platforms))
+    monkeypatch.setattr(jax.config, "read", lambda key: name)
+    assert pf.requested_platform() == want
+
+
+# ---------------------------------------------------------------------------
+# persistent compilation cache
+# ---------------------------------------------------------------------------
+
+def test_use_compile_cache_leaves_an_env_dir_to_jax(monkeypatch):
+    seen = {}
+    monkeypatch.setattr(
+        jax.config, "update", lambda k, v: seen.__setitem__(k, v))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert pf.use_compile_cache() == "/elsewhere/cache"
+    assert seen == {}                      # nothing set in code
+
+
+def test_use_compile_cache_defaults_to_the_fixed_repo_path(monkeypatch):
+    seen = {}
+    monkeypatch.setattr(
+        jax.config, "update", lambda k, v: seen.__setitem__(k, v))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    path = pf.use_compile_cache()
+    assert path == pf.COMPILE_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    assert seen == {"jax_compilation_cache_dir": path}
+    assert pf.use_compile_cache() == path   # same path every call
 
 
 def test_describe_reports_live_process_state():
