@@ -166,6 +166,7 @@ from repro.events import synthetic as syn
 from repro.hw import energy_model
 from repro.serve import fidelity as fidelity_mod
 from repro.serve import spec as spec_mod
+from repro.serve.trace import span
 
 __all__ = [
     "POLICIES", "QoSClass", "DEFAULT_QOS", "GESTURE_TIER", "TELEMETRY_TIER",
@@ -1061,21 +1062,32 @@ class StreamRuntime:
         overload), coalesce per tier, dispatch scatter + spec read(s),
         sync the *previous* read (one host sync).  Returns this step's
         record (its ``latency_s``/``digest`` fill at the next sync).
-        With ``pipeline=False`` the sync is this step's own read."""
-        self._maybe_shrink()
-        scheduled, deferred, overload, barrier = self._schedule(t_deadline)
-        for s in deferred:
-            s.deferrals += s.queued
-        groups, copies, n_events, order = self._coalesce(
-            scheduled, t_deadline)
-        specs = self._step_specs(scheduled)
+        With ``pipeline=False`` the sync is this step's own read.
+        Traced as ``serve.step`` with its phases as child spans
+        (``serve.trace``)."""
+        with span("serve.step", deadline=self.n_steps):
+            return self._step(t_deadline)
+
+    def _step(self, t_deadline: float) -> StepRecord:
+        with span("serve.schedule") as sp:
+            self._maybe_shrink()
+            scheduled, deferred, overload, barrier = self._schedule(
+                t_deadline)
+            for s in deferred:
+                s.deferrals += s.queued
+            sp.set_metadata(scheduled=len(scheduled), deferred=len(deferred))
+        with span("serve.coalesce") as sp:
+            groups, copies, n_events, order = self._coalesce(
+                scheduled, t_deadline)
+            specs = self._step_specs(scheduled)
+            self._account_step_energy(t_deadline)
+            sp.set_metadata(events=n_events, chunks=len(copies))
         # the engine reads in epoch-rebased time, same basis the queued
         # stamps were rebased to at offer time (scheduling above stays
         # absolute); recorded as-rebased so the replay oracle consumes
         # the log verbatim
         t_read = t_deadline - (self.t_epoch or 0.0)
         noise_step = self.n_steps   # the analog-fidelity noise key input
-        self._account_step_energy(t_deadline)
         wall0 = time.perf_counter()
         for _tier, items in groups:
             if self._use_ring:
@@ -1129,7 +1141,11 @@ class StreamRuntime:
         return record
 
     def _sync(self, fl: _Inflight) -> None:
-        jax.block_until_ready(fl.products_list)
+        """Deliver one deadline's products: wait for the device, copy
+        them to the host, record latency and the replay digest."""
+        k = fl.record.noise_step
+        with span("serve.sync", deadline=k):
+            jax.block_until_ready(fl.products_list)
         lat = time.perf_counter() - fl.record.wall_dispatch
         fl.record.latency_s = lat
         if len(self.latencies_s) < self._max_lat:
@@ -1138,7 +1154,18 @@ class StreamRuntime:
             samples = self.latencies_by_tier.setdefault(tier, [])
             if len(samples) < self._max_lat:
                 samples.append(lat)
-        fl.record.digest = digest_step(fl.products_list)
+        # copy every product to the host in the digest's order (an array
+        # two specs share, once); the digest then hashes the host copy
+        # each array keeps
+        with span("serve.readback", deadline=k) as sp:
+            arrays = {id(a): a for p in fl.products_list
+                      for _, a in sorted(p.items())}
+            sp.set_metadata(bytes=sum(np.asarray(a).nbytes
+                                      for a in arrays.values()))
+        with span("serve.digest", deadline=k,
+                  bytes=sum(a.nbytes for p in fl.products_list
+                            for a in p.values())):
+            fl.record.digest = digest_step(fl.products_list)
 
     def flush(self) -> Optional[Dict[str, jax.Array]]:
         """Sync the in-flight read (if any) and return its *primary*

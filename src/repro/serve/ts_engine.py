@@ -127,6 +127,7 @@ from repro.kernels import ops
 from repro.serve import fidelity as fidelity_mod
 from repro.serve import spec as spec_mod
 from repro.serve.api import SensorSession
+from repro.serve.trace import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -934,12 +935,23 @@ class IngestRing:
     masks every invalid event to -inf before it can touch a surface bit,
     so ring-staged and host-staged ingest are bitwise identical — the
     replay-oracle digest gate holds on either path.
+
+    Reuse needs an upload that copies.  Where ``device_put`` may alias
+    the NumPy buffer instead (the CPU backend hands it to the
+    computation zero-copy), a set rewritten ``depth`` pushes later can
+    still be read by a queued scatter — two tier groups of one padded
+    size reuse their sets at the very next deadline.  ``reuse=False``
+    therefore hands out a fresh set on every acquire; the set then
+    lives exactly as long as the arrays that alias it.  On the TPU the
+    upload copies into device memory and sets are reused, with no
+    extra host copy.
     """
 
-    def __init__(self, capacity: int, depth: int = 2):
+    def __init__(self, capacity: int, depth: int = 2, reuse: bool = True):
         assert depth >= 2, depth
         self.capacity = capacity
         self.depth = depth
+        self.reuse = reuse
         self._sets: Dict[int, List[dict]] = {}   # padded B -> staging sets
         self._turn: Dict[int, int] = {}
 
@@ -957,6 +969,8 @@ class IngestRing:
     def acquire(self, b: int) -> dict:
         """The next staging set for padded batch size ``b``, zero-filled
         (pad rows must stay scatter no-ops)."""
+        if not self.reuse:
+            return self._alloc(b)
         sets = self._sets.get(b)
         if sets is None:
             sets = self._sets[b] = [self._alloc(b) for _ in range(self.depth)]
@@ -1064,7 +1078,10 @@ class TimeSurfaceEngine:
         self._rest_cache: Dict[spec_mod.ReadoutSpec,
                                Optional[spec_mod.ReadoutSpec]] = {}
         self._warned: set = set()
-        self._ring = IngestRing(cfg.chunk_capacity)
+        devices = mesh.devices.flat if mesh is not None else jax.devices()[:1]
+        self._ring = IngestRing(
+            cfg.chunk_capacity,
+            reuse=all(d.platform != "cpu" for d in devices))
         _, _, tp = cfg.tile_counts()
         self._max_dirty = (
             self._plan.max_dirty if self._plan
@@ -1398,36 +1415,47 @@ class TimeSurfaceEngine:
         bit (the replay-oracle digest gate covers both paths).
         """
         cap = self.cfg.chunk_capacity
-        rows: List[Tuple[int, RawPart]] = []
-        for slot, part in items:
-            if isinstance(slot, SensorSession):
-                slot._check()
-                slot = slot.slot
-            self._check_acquired(slot)
-            assert len(part[0]) <= cap, (
-                f"part of {len(part[0])} events exceeds chunk capacity "
-                f"{cap}; split parts host-side (see StreamRuntime._coalesce)"
-            )
-            rows.append((slot, part))
-        if not rows:
-            return
-        if self._plan:
-            sids, ev = self._stage_sharded(rows)
-            self.state = self._plan.ingest(self.state, sids, ev)
-            return
-        buf = self._ring.acquire(self._pad_batch(len(rows)))
-        for i, (slot, part) in enumerate(rows):
-            IngestRing.fill_row(buf, i, slot, part)
-        sids, ev = IngestRing.upload(buf)
-        self.state = ingest_step_donated(
-            self.state, sids, ev, polarities=self.cfg.polarities
-        )
+        with span("serve.ingest", capacity=cap) as sp:
+            rows: List[Tuple[int, RawPart]] = []
+            n_events = 0
+            for slot, part in items:
+                if isinstance(slot, SensorSession):
+                    slot._check()
+                    slot = slot.slot
+                self._check_acquired(slot)
+                assert len(part[0]) <= cap, (
+                    f"part of {len(part[0])} events exceeds chunk capacity "
+                    f"{cap}; split parts host-side (see "
+                    f"StreamRuntime._coalesce)"
+                )
+                rows.append((slot, part))
+                n_events += len(part[0])
+            if not rows:
+                return
+            with span("serve.stage"):
+                if self._plan:
+                    buf = self._stage_sharded(rows)
+                else:
+                    buf = self._ring.acquire(self._pad_batch(len(rows)))
+                    for i, (slot, part) in enumerate(rows):
+                        IngestRing.fill_row(buf, i, slot, part)
+            sp.set_metadata(events=n_events, rows=len(rows),
+                            padded_rows=len(buf["sids"]))
+            with span("serve.upload"):
+                sids, ev = IngestRing.upload(
+                    buf, put=self._plan.place if self._plan else jax.device_put)
+            if self._plan:
+                self.state = self._plan.ingest(self.state, sids, ev)
+            else:
+                self.state = ingest_step_donated(
+                    self.state, sids, ev, polarities=self.cfg.polarities
+                )
 
-    def _stage_sharded(self, rows: Sequence[Tuple[int, RawPart]]):
+    def _stage_sharded(self, rows: Sequence[Tuple[int, RawPart]]) -> dict:
         """Shard-major ring staging mirroring ``_ShardPlan.route``: rows
-        group by the shard owning their slot (ids go local), every shard
-        pads to a common power-of-two row count, and the upload lands
-        pre-sharded (``_ShardPlan.place``) so shard_map's block split
+        group by the shard owning their slot (ids go local) and every
+        shard pads to a common power-of-two row count; uploaded
+        pre-sharded (``_ShardPlan.place``), shard_map's block split
         hands each device exactly the rows targeting its slots."""
         plan = self._plan
         per_shard: List[List[Tuple[int, RawPart]]] = [
@@ -1441,7 +1469,7 @@ class TimeSurfaceEngine:
         for shard, shard_rows in enumerate(per_shard):
             for j, (local, part) in enumerate(shard_rows):
                 IngestRing.fill_row(buf, shard * b_local + j, local, part)
-        return IngestRing.upload(buf, put=plan.place)
+        return buf
 
     def _ingest_labeled(self, items: Sequence[IngestItem]) -> list:
         """Scatter payloads *and* label each event with its STCF support
@@ -1617,37 +1645,38 @@ class TimeSurfaceEngine:
         streaming pipeline's single host sync per deadline).
         """
         uniq = list(dict.fromkeys(specs))
-        groups: Dict[spec_mod.ReadoutSpec,
-                     List[spec_mod.ReadoutSpec]] = {}
-        for sp in uniq:
-            self._check_spec(sp)
-            groups.setdefault(self._compiled(sp).stage0, []).append(sp)
-        out: Dict[spec_mod.ReadoutSpec, Dict[str, jax.Array]] = {}
-        for stage0, members in groups.items():
-            if len(members) == 1:
-                out[members[0]] = self.read(members[0], t_now,
-                                            noise_step=noise_step)
-                continue
-            base = self.read(stage0, t_now,   # one shared stage-0 dispatch
-                             noise_step=noise_step)
-            for sp in members:
-                compiled = self._compiled(sp)
-                if not compiled.has_heads:    # sp IS the stage-0 spec
-                    out[sp] = dict(base)
+        with span("serve.read", specs=len(uniq)):
+            groups: Dict[spec_mod.ReadoutSpec,
+                         List[spec_mod.ReadoutSpec]] = {}
+            for sp in uniq:
+                self._check_spec(sp)
+                groups.setdefault(self._compiled(sp).stage0, []).append(sp)
+            out: Dict[spec_mod.ReadoutSpec, Dict[str, jax.Array]] = {}
+            for stage0, members in groups.items():
+                if len(members) == 1:
+                    out[members[0]] = self.read(members[0], t_now,
+                                                noise_step=noise_step)
                     continue
-                head_params = self._resolved(sp)[2]
-                inputs = {n: base[n] for n in compiled.stage0.names}
-                if self._plan:
-                    heads_out = self._plan.head_reader(compiled)(
-                        inputs, head_params
-                    )
-                else:
-                    heads_out = read_head_products(
-                        inputs, head_params, compiled=compiled, cfg=self.cfg
-                    )
-                merged = {**base, **heads_out}
-                out[sp] = {n: merged[n] for n in sp.names}
-        return {sp: out[sp] for sp in uniq}
+                base = self.read(stage0, t_now,   # one shared stage-0 dispatch
+                                 noise_step=noise_step)
+                for sp in members:
+                    compiled = self._compiled(sp)
+                    if not compiled.has_heads:    # sp IS the stage-0 spec
+                        out[sp] = dict(base)
+                        continue
+                    head_params = self._resolved(sp)[2]
+                    inputs = {n: base[n] for n in compiled.stage0.names}
+                    if self._plan:
+                        heads_out = self._plan.head_reader(compiled)(
+                            inputs, head_params
+                        )
+                    else:
+                        heads_out = read_head_products(
+                            inputs, head_params, compiled=compiled,
+                            cfg=self.cfg)
+                    merged = {**base, **heads_out}
+                    out[sp] = {n: merged[n] for n in sp.names}
+            return {sp: out[sp] for sp in uniq}
 
     def serve_step(
         self,
