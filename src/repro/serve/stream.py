@@ -150,9 +150,12 @@ through a fresh engine (``events.replay.oracle_digests``).
 """
 from __future__ import annotations
 
+import concurrent.futures
 import dataclasses
 import hashlib
 import math
+import os
+import threading
 import time
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
@@ -604,8 +607,10 @@ class StepRecord:
     deadline) per scheduled sensor, in drain order; ``deferred`` lists
     the sensors overload pushed past this step as (slot, tier, queued);
     ``specs`` are the ReadoutSpecs this step read (primary first);
-    ``digest`` is the SHA-256 of the synced products, filled at sync
-    time, which the synchronous oracle must reproduce bitwise.
+    ``digest`` is the SHA-256 hash tree of the synced products
+    (``digest_step``: per array a name/shape/dtype header, then the
+    digests of its 1 MiB leaves in order), filled at sync time, which
+    the synchronous oracle must reproduce bitwise.
     ``latency_s`` is dispatch -> sync-returned wall time (in pipelined
     mode the sync happens at the next deadline, so it is the latency the
     *consumer* of the previous frame observes).
@@ -636,29 +641,105 @@ class StepRecord:
 LogEntry = Tuple[str, Union[int, Tuple, StepRecord]]
 
 
+#: bytes per leaf of the replay digest's hash tree
+DIGEST_LEAF_BYTES = 1 << 20
+
+#: copies and hashes for the step digests; ``numpy`` copies and
+#: ``hashlib`` release the GIL, so the work spreads over every core
+_DIGEST_POOL = concurrent.futures.ThreadPoolExecutor(
+    max_workers=os.cpu_count() or 1, thread_name_prefix="digest")
+
+
+def _hash_leaf(leaf: np.ndarray) -> Tuple[bytes, int]:
+    return hashlib.sha256(leaf).digest(), threading.get_ident()
+
+
+def _copy_rows(job: Tuple[np.ndarray, np.ndarray, slice]) -> int:
+    out, host, rows = job
+    np.copyto(out[rows], host[rows])
+    return threading.get_ident()
+
+
+def _digest(products_list: Sequence[Dict[str, jax.Array]]
+            ) -> Tuple[str, int, int]:
+    """``(hex digest, leaves hashed, threads that took part)`` of one
+    step's reads; see ``digest_products`` and ``digest_step``."""
+    hosts: Dict[int, np.ndarray] = {}   # an array two specs share, once
+    for products in products_list:
+        for a in products.values():
+            if id(a) not in hosts:
+                hosts[id(a)] = np.asarray(a)    # readback's cached copy
+    run = (map if sum(h.nbytes for h in hosts.values()) <= DIGEST_LEAF_BYTES
+           else _DIGEST_POOL.map)
+    # the leaves cut C-order bytes.  A TPU's host copy keeps the device
+    # layout (pool-shaped products: the two minor dims swapped), so it
+    # is brought into C order first, in blocks of about a leaf of rows
+    bufs: Dict[int, np.ndarray] = {}
+    copies = []
+    for key, host in hosts.items():
+        if host.flags.c_contiguous:
+            bufs[key] = host
+            continue
+        bufs[key] = out = np.empty(host.shape, host.dtype)
+        step = max(1, DIGEST_LEAF_BYTES * host.shape[0] // host.nbytes)
+        copies += [(out, host, slice(i, i + step))
+                   for i in range(0, host.shape[0], step)]
+    threads = set(run(_copy_rows, copies))
+    placed: Dict[int, Tuple[int, int]] = {}     # id -> its leaves
+    leaves: List[np.ndarray] = []
+    for key, buf in bufs.items():
+        flat = buf.reshape(-1).view(np.uint8)
+        lo = len(leaves)
+        leaves.extend(flat[i:i + DIGEST_LEAF_BYTES] for i in
+                      range(0, max(flat.size, 1), DIGEST_LEAF_BYTES))
+        placed[key] = (lo, len(leaves))
+    hashed = list(run(_hash_leaf, leaves))
+    threads.update(t for _, t in hashed)
+
+    def tree(products):
+        h = hashlib.sha256()
+        for name in sorted(products):
+            key = id(products[name])
+            lo, hi = placed[key]
+            h.update(name.encode())
+            h.update(str(hosts[key].shape).encode())
+            h.update(str(hosts[key].dtype).encode())
+            for leaf_digest, _ in hashed[lo:hi]:
+                h.update(leaf_digest)
+        return h.hexdigest()
+
+    if len(products_list) == 1:
+        hexdigest = tree(products_list[0])
+    else:
+        h = hashlib.sha256()
+        for products in products_list:
+            h.update(tree(products).encode())
+        hexdigest = h.hexdigest()
+    return hexdigest, len(leaves), len(threads)
+
+
 def digest_products(products: Dict[str, jax.Array]) -> str:
-    """SHA-256 over the (name-sorted) product arrays' raw bytes — the
-    bitwise-equality currency of the replay oracle gate."""
-    h = hashlib.sha256()
-    for name in sorted(products):
-        a = np.asarray(products[name])
-        h.update(name.encode())
-        h.update(str(a.shape).encode())
-        h.update(str(a.dtype).encode())
-        h.update(a.tobytes())
-    return h.hexdigest()
+    """SHA-256 hash tree over the product arrays' bytes — the
+    bitwise-equality currency of the replay oracle gate.
+
+    For each array in name order the root hashes a header (the name,
+    then ``str`` of the shape and of the dtype) and then, in order, the
+    raw 32-byte SHA-256 of each ``DIGEST_LEAF_BYTES`` leaf of the
+    array's C-order bytes (the last leaf shorter; an empty array is one
+    empty leaf).  The leaves of a C-contiguous array are views of it;
+    an array in another layout is copied into C order first.  Copies
+    and leaves run on a thread pool unless the whole payload fits one
+    leaf; the digest depends only on the names, shapes, dtypes and
+    bytes, never on the threads."""
+    return _digest([products])[0]
 
 
 def digest_step(products_list: Sequence[Dict[str, jax.Array]]) -> str:
-    """Digest of one step's reads.  A single-spec step digests exactly
-    as before (``digest_products``) so pre-QoS digests stay comparable;
-    a multi-spec step chains the per-spec digests in read order."""
-    if len(products_list) == 1:
-        return digest_products(products_list[0])
-    h = hashlib.sha256()
-    for products in products_list:
-        h.update(digest_products(products).encode())
-    return h.hexdigest()
+    """Digest of one step's reads: a single-spec step's is its
+    ``digest_products``; a multi-spec step chains the per-spec hex
+    digests in read order under one more SHA-256.  All leaves of the
+    step hash in one batch, an array two specs share once."""
+    return _digest(products_list)[0]
 
 
 class _Inflight:
@@ -1164,8 +1245,9 @@ class StreamRuntime:
                                       for a in arrays.values()))
         with span("serve.digest", deadline=k,
                   bytes=sum(a.nbytes for p in fl.products_list
-                            for a in p.values())):
-            fl.record.digest = digest_step(fl.products_list)
+                            for a in p.values())) as sp:
+            fl.record.digest, leaves, workers = _digest(fl.products_list)
+            sp.set_metadata(leaves=leaves, workers=workers)
 
     def flush(self) -> Optional[Dict[str, jax.Array]]:
         """Sync the in-flight read (if any) and return its *primary*

@@ -29,7 +29,8 @@ span               work                                  counts
 ``serve.read``     enqueue of the read and head programs ``specs``
 ``serve.sync``     wait for a deadline's products        ``deadline``
 ``serve.readback`` device-to-host copies of them         ``deadline``, ``bytes``
-``serve.digest``   SHA-256 replay digest of them         ``deadline``, ``bytes``
+``serve.digest``   SHA-256 replay digest of them, a      ``deadline``, ``bytes``,
+                   tree of 1 MiB leaves on a thread pool ``leaves``, ``workers``
 =================  ====================================  =============================
 
 ``deadline`` is the runtime's step index (``StepRecord.noise_step``):

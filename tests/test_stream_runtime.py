@@ -6,6 +6,8 @@ pure function of event timestamps and the deadline grid, so every
 assertion below is exact (drop *counts*, chunk *sizes*, bitwise
 surfaces), not statistical.
 """
+import hashlib
+
 import numpy as np
 import pytest
 
@@ -844,6 +846,138 @@ def test_long_horizon_replay_oracle():
                 h=H, w=W))
         got = oracle.read(rt.spec, e.t_read)
         assert stream.digest_products(got) == digests.pop(0)
+
+
+# ---------------------------------------------------------------------------
+# replay digest: a SHA-256 tree of 1 MiB leaves
+# ---------------------------------------------------------------------------
+
+LEAF = stream.DIGEST_LEAF_BYTES
+
+
+def tree_digest(products):
+    """The digest's layout, spelled out: per array in name order the
+    header, then the raw digest of each leaf of its C-order bytes."""
+    h = hashlib.sha256()
+    for name in sorted(products):
+        a = np.asarray(products[name])
+        h.update(name.encode())
+        h.update(str(a.shape).encode())
+        h.update(str(a.dtype).encode())
+        raw = a.tobytes()
+        for i in range(0, max(len(raw), 1), LEAF):
+            h.update(hashlib.sha256(raw[i:i + LEAF]).digest())
+    return h.hexdigest()
+
+
+def pool_products(seed=0):
+    """Pool-shaped products over more than one leaf: surface and count
+    two leaves each (the second partial), logits under one."""
+    rng = np.random.default_rng(seed)
+    return {"surface": rng.random((2, 2, 240, 320), dtype=np.float32),
+            "count": rng.integers(0, 16, (2, 2, 240, 320)).astype(np.int32),
+            "logits": rng.standard_normal((2, 10)).astype(np.float32)}
+
+
+def minor_dims_swapped(a):
+    """``a`` laid out as a TPU's host copy of a pool-shaped product is:
+    the same values, the two minor dims swapped in memory."""
+    return np.ascontiguousarray(a.swapaxes(-1, -2)).swapaxes(-1, -2)
+
+
+@pytest.mark.parametrize("workers", [1, 2, 16])
+def test_digest_is_the_leaf_tree_whatever_the_workers(monkeypatch, workers):
+    from concurrent.futures import ThreadPoolExecutor
+
+    prods = pool_products()
+    prods["stcf"] = minor_dims_swapped(
+        np.random.default_rng(1).random((4, 2, 240, 320), dtype=np.float32))
+    assert not prods["stcf"].flags.c_contiguous
+    assert prods["surface"].nbytes > LEAF > prods["logits"].nbytes
+    with ThreadPoolExecutor(max_workers=workers) as pool:
+        monkeypatch.setattr(stream, "_DIGEST_POOL", pool)
+        got, leaves, used = stream._digest([prods])
+        assert got == stream.digest_products(prods)
+    assert got == tree_digest(prods)
+    assert leaves == 2 + 2 + 1 + 3
+    assert 1 <= used <= workers
+
+
+@pytest.mark.parametrize("products", [
+    {"surface": np.zeros((4, 24, 32), np.float32)},
+    {"empty": np.zeros((0, 3), np.float32), "scalar": np.float32(2.5)},
+    {"mask": np.ones((LEAF + 1,), bool), "logits": np.arange(6.0)},
+], ids=["one-small-leaf", "empty-and-scalar", "bool-over-a-leaf"])
+def test_digest_layout_edge_shapes(products):
+    assert stream.digest_products(products) == tree_digest(products)
+    assert stream._digest([products])[1] == sum(
+        max(1, -(-np.asarray(a).nbytes // LEAF)) for a in products.values())
+
+
+@pytest.mark.parametrize("name,byte", [
+    ("surface", LEAF - 1),          # last byte of the first leaf
+    ("surface", LEAF),              # first byte of the second
+    ("surface", -1),                # last byte of the last leaf
+    ("count", LEAF),
+    ("logits", 0),                  # an array under one leaf
+    ("logits", -1),
+])
+def test_digest_sees_one_flipped_byte(name, byte):
+    prods = pool_products()
+    base = stream.digest_products(prods)
+    prods[name].reshape(-1).view(np.uint8)[byte] ^= 1
+    assert stream.digest_products(prods) != base
+
+
+@pytest.mark.parametrize("change", ["shape", "dtype", "name"])
+def test_digest_sees_the_header(change):
+    prods = pool_products()
+    base = stream.digest_products(prods)
+    a = prods["surface"]
+    if change == "shape":
+        prods["surface"] = a.reshape(4, 240, 320)
+    elif change == "dtype":
+        prods["surface"] = a.view(np.int32)
+    else:
+        prods["surfaces"] = prods.pop("surface")
+    assert stream.digest_products(prods) != base
+
+
+@pytest.mark.parametrize("layout", ["strided", "transposed", "fortran",
+                                    "minor-dims-swapped", "read-only",
+                                    "device"])
+def test_digest_of_a_view_is_that_of_its_contiguous_copy(layout):
+    import jax.numpy as jnp
+
+    base = np.random.default_rng(1).random((4, 2, 240, 320),
+                                           dtype=np.float32)
+    a = {"strided": lambda: base[::2],
+         "transposed": lambda: base.transpose(0, 1, 3, 2),
+         "fortran": lambda: np.asfortranarray(base),
+         "minor-dims-swapped": lambda: minor_dims_swapped(base),
+         "read-only": lambda: np.asarray(jnp.asarray(base)),
+         "device": lambda: jnp.asarray(base)}[layout]()
+    host = np.asarray(a)
+    if layout != "device":
+        assert not (host.flags.c_contiguous and host.flags.writeable)
+    want = stream.digest_products({"surface": np.ascontiguousarray(host)})
+    assert stream.digest_products({"surface": a}) == want
+
+
+def test_digest_of_an_array_two_specs_share():
+    """Its leaves hash once, and the step digests as with two copies."""
+    p = pool_products()
+    stage0 = {"surface": p["surface"], "logits": p["logits"]}
+    shared = [stage0, {"surface": p["surface"], "count": p["count"]}]
+    copied = [stage0, {"surface": p["surface"].copy(), "count": p["count"]}]
+    got, leaves, _ = stream._digest(shared)
+    want, leaves_copied, _ = stream._digest(copied)
+    assert got == want == stream.digest_step(copied)
+    assert leaves == leaves_copied - 2 == 5
+    h = hashlib.sha256()
+    for prods in copied:
+        h.update(tree_digest(prods).encode())
+    assert got == h.hexdigest()
 
 
 # ---------------------------------------------------------------------------

@@ -136,8 +136,13 @@ def test_spans_nest_per_deadline_and_count_the_work(tmp_path, pipeline, mesh):
             == sum(r.n_events for r in recs) > 0)
     for k, read in enumerate(eng.reads):
         nbytes = sum(a.nbytes for prods in read.values() for a in prods.values())
-        for name in ("serve.readback", "serve.digest"):
-            assert by[name][k][3] == {"deadline": k, "bytes": nbytes}
+        assert by["serve.readback"][k][3] == {"deadline": k, "bytes": nbytes}
+        arrays = {id(a): a for prods in read.values() for a in prods.values()}
+        leaves = sum(max(1, -(-a.nbytes // st.DIGEST_LEAF_BYTES))
+                     for a in arrays.values())
+        digest = dict(by["serve.digest"][k][3])
+        assert digest.pop("workers") >= 1
+        assert digest == {"deadline": k, "bytes": nbytes, "leaves": leaves}
 
 
 def test_digests_do_not_depend_on_the_profiler(tmp_path):
