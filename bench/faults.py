@@ -23,6 +23,19 @@ class HalfBatch(harness.Tap):
         return self._engine.push_staged(list(items)[: len(items) // 2])
 
 
+class Unrouted(harness.Tap):
+    """The routing between chips left out: every row goes to the slot of
+    the same local index on the first chip, so the events of sensors
+    held by the other chips never reach them (no fault on one chip)."""
+
+    def push_staged(self, items):
+        eng = self._engine
+        per_chip = eng.n_slots_padded // len(
+            eng.state.surfaces.sae.sharding.device_set)
+        return eng.push_staged(
+            [(slot % per_chip, part) for slot, part in items])
+
+
 class Altered(harness.Tap):
     """One answer altered where it is produced: one cell of every
     product of every slot moves."""
@@ -62,4 +75,5 @@ FAULTS = {"none": (harness.Tap, contextlib.nullcontext),
           "unchanged": (Unchanged, contextlib.nullcontext),
           "half_batch": (HalfBatch, contextlib.nullcontext),
           "altered": (Altered, contextlib.nullcontext),
+          "unrouted": (Unrouted, contextlib.nullcontext),
           "unserved": (harness.Tap, unserved)}

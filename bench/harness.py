@@ -16,8 +16,9 @@ gives it:
 
 The run: traffic from the seed, the engine and its stream runtime over
 the program's public API, head weights drawn from the seed on the
-device, sensors connected in waves, every ingest batch shape and both
-read programs warmed, then a closed loop on the virtual 10 ms deadline
+device, sensors connected in waves in the fleet's order
+(``generator.fleet``), every ingest batch shape and both read programs
+warmed, then a closed loop on the virtual 10 ms deadline
 grid for ``seconds`` of wall time (offers in arrival granules, then
 ``runtime.step``; no sleeping), then ``flush``.  Once the window has
 closed and device memory has been read, a seeded sample of the products
@@ -213,6 +214,26 @@ def build_specs(config: dict, head_key: str):
             for t in config["tiers"]}
 
 
+def build_engine(config: dict, specs: dict, backend: Optional[str] = None):
+    """The configuration's engine over its tiers' specs, its slot pool
+    sharded over ``mesh_devices`` devices where that is above 1."""
+    from repro.serve import ts_engine as te
+
+    c = config
+    ecfg = te.TSEngineConfig(
+        h=c["h"], w=c["w"], polarities=c["polarities"],
+        n_slots=c["n_slots"], chunk_capacity=c["chunk_capacity"],
+        mode=c["mode"], cmem_f=c["cmem_f"], tau_tw=c["tau_tw"],
+        stcf_radius=c["stcf_radius"], stcf_threshold=c["stcf_threshold"],
+        backend=backend, specs=tuple(specs.values()))
+    mesh = None
+    if c.get("mesh_devices", 1) > 1:
+        from repro.launch.mesh import make_host_mesh
+
+        mesh = make_host_mesh(c["mesh_devices"])
+    return te.TimeSurfaceEngine(ecfg, mesh=mesh)
+
+
 def head_product(config: dict):
     for tier in config["tiers"]:
         for prod in tier["products"].values():
@@ -264,18 +285,22 @@ class Feed:
         return tuple(np.concatenate([q[i] for q in parts]) for i in range(4))
 
 
-def batch_sizes(config: dict, traffic, n_steps: int) -> List[int]:
-    """Every padded ingest batch the runtime can form over ``n_steps``
-    deadlines: each served sensor drains into ceil(n / chunk_capacity)
-    chunks of at most ``queue_capacity`` events, chunks of one tier
-    share a dispatch, and dispatches pad to powers of two.  Returns all
-    powers of two up to the largest."""
+def batch_sizes(config: dict, traffic, n_steps: int,
+                chip_of: Optional[List[int]] = None) -> List[int]:
+    """Every padded ingest batch, in rows per chip, the runtime can form
+    over ``n_steps`` deadlines: each served sensor drains into
+    ceil(n / chunk_capacity) chunks of at most ``queue_capacity``
+    events, chunks of one tier share a dispatch, a sharded dispatch
+    pads every chip to its fullest chip's rows (sensor ``i`` is on chip
+    ``chip_of[i]``, all on one without it), and rows pad to powers of
+    two.  Returns all powers of two up to the largest."""
     d, cap_q = config["deadline_s"], config["queue_capacity"]
     cap_c = config["chunk_capacity"]
     periods = {t["tier"]: period_of(config, t) for t in config["tiers"]}
     per_step: Dict[tuple, int] = {}
     deadlines = np.arange(1, n_steps + 1) * d
-    for tr in traffic:
+    for i, tr in enumerate(traffic):
+        chip = chip_of[i] if chip_of else 0
         steps = ref.served_steps(periods[tr.tier], d, n_steps)
         ends = deadlines[np.asarray(steps) - 1]
         loops = np.floor(ends / tr.segment_s)
@@ -283,7 +308,8 @@ def batch_sizes(config: dict, traffic, n_steps: int) -> List[int]:
             tr.t.astype(np.float64), ends - loops * tr.segment_s, side="left")
         drained = np.minimum(np.diff(np.concatenate([[0], before])), cap_q)
         for k, n in zip(steps, drained):
-            per_step[k, tr.tier] = per_step.get((k, tr.tier), 0) + -(-int(n) // cap_c)
+            key = (k, tr.tier, chip)
+            per_step[key] = per_step.get(key, 0) + -(-int(n) // cap_c)
     most = max(per_step.values(), default=1)
     out, b = [], 1
     while b < 2 * most:
@@ -315,7 +341,6 @@ class Deployment:
 
         from repro.serve import heads
         from repro.serve import stream as st
-        from repro.serve import ts_engine as te
 
         c = self.config = cell.config
         t0 = time.perf_counter()
@@ -334,18 +359,7 @@ class Deployment:
                 head["n_classes"], head["width"])
             heads.register_head_params(head_key, self.head_params)
         self.specs = build_specs(c, head_key)
-        ecfg = te.TSEngineConfig(
-            h=c["h"], w=c["w"], polarities=c["polarities"],
-            n_slots=c["n_slots"], chunk_capacity=c["chunk_capacity"],
-            mode=c["mode"], cmem_f=c["cmem_f"], tau_tw=c["tau_tw"],
-            stcf_radius=c["stcf_radius"], stcf_threshold=c["stcf_threshold"],
-            backend=backend, specs=tuple(self.specs.values()))
-        mesh = None
-        if c.get("mesh_devices", 1) > 1:
-            from repro.launch.mesh import make_host_mesh
-
-            mesh = make_host_mesh(c["mesh_devices"])
-        self.engine = te.TimeSurfaceEngine(ecfg, mesh=mesh)
+        self.engine = build_engine(c, self.specs, backend)
         self.tap = tap(self.engine)
         scfg = st.StreamConfig(policy=c["policy"],
                                queue_capacity=c["queue_capacity"],
@@ -364,6 +378,8 @@ class Deployment:
                 self.sensors.append(self.runtime.connect(qos[tr.tier]))
             jax.block_until_ready(self.engine.state)
         self.slot_of = [s.slot for s in self.sensors]
+        per_chip = self.engine.n_slots_padded // c.get("mesh_devices", 1)
+        self.chip_of = [s // per_chip for s in self.slot_of]
         self.sensor_of_slot = {s: i for i, s in enumerate(self.slot_of)}
         self.feeds = [Feed(tr) for tr in self.traffic]
         self.granules = int(cell.mix["arrival_granules"])
@@ -374,12 +390,13 @@ class Deployment:
     # -- set-up ---------------------------------------------------------------
     def warm_ingest(self, n_steps: int) -> List[int]:
         """Dispatch every ingest batch shape the traffic forms, with
-        empty chunks (no state changes)."""
+        empty chunks (no state changes): ``b`` rows on the first slot's
+        chip form the batch of ``b`` rows a chip."""
         import jax
 
         empty = (np.zeros(0, np.int32), np.zeros(0, np.int32),
                  np.zeros(0, np.float32), np.zeros(0, np.int32))
-        sizes = batch_sizes(self.config, self.traffic, n_steps)
+        sizes = batch_sizes(self.config, self.traffic, n_steps, self.chip_of)
         for b in sizes:
             self.engine.push_staged([(self.slot_of[0], empty)] * b)
         jax.block_until_ready(self.engine.state)
@@ -442,6 +459,7 @@ def _capture(dep: Deployment, read, info: StepInfo, rng) -> List[dict]:
         slot = dep.slot_of[i]
         prods = read[dep.specs[tier]]
         out.append({"k": info.k, "t": info.t, "sensor": i, "tier": tier,
+                    "chip": dep.chip_of[i],
                     "products": {n: np.array(np.asarray(a)[slot])
                                  for n, a in prods.items()}})
     return out
@@ -496,21 +514,22 @@ def run_window(dep: Deployment, seconds: float, meter: CompileMeter,
 # ----------------------------------------------------------------------------
 
 def pick_samples(samples: List[dict], seed: int, n: int = SAMPLES) -> List[dict]:
-    """A seeded draw of ``n`` captured products, spread over the tiers,
-    always holding the window's last deadline (the longest history)."""
+    """A seeded draw of ``n`` captured products, spread over the tiers
+    and, within each, over the chips that hold them, always holding the
+    window's last deadline (the longest history)."""
     if not samples:
         return []
     last_k = max(s["k"] for s in samples)
     keep = [s for s in samples if s["k"] == last_k]
     rest = [s for s in samples if s["k"] != last_k]
     random.Random(seed).shuffle(rest)
-    by_tier: Dict[str, List[dict]] = {}
+    groups: Dict[tuple, List[dict]] = {}
     for s in rest:
-        by_tier.setdefault(s["tier"], []).append(s)
-    while len(keep) < n and any(by_tier.values()):
-        for tier in sorted(by_tier):
-            if by_tier[tier] and len(keep) < n:
-                keep.append(by_tier[tier].pop())
+        groups.setdefault((s["tier"], s["chip"]), []).append(s)
+    while len(keep) < n and any(groups.values()):
+        for key in sorted(groups):
+            if groups[key] and len(keep) < n:
+                keep.append(groups[key].pop())
     return keep
 
 
@@ -655,8 +674,9 @@ class Context:
     trace: tracing.Trace     # the traced window
     steps: List[StepInfo]    # its deadlines
     config: dict
-    peaks: Optional[Dict[str, float]]   # the device's peak rates
+    peaks: Optional[Dict[str, float]]   # one chip's peak rates
     window_s: float          # wall seconds of the window
+    chips: int               # the cell's chips (``BENCHMARK.json``)
 
 
 def per_layer(cell: Cell, win: Window, tdir: str) -> dict:
@@ -670,7 +690,7 @@ def per_layer(cell: Cell, win: Window, tdir: str) -> dict:
         shutil.rmtree(tdir, ignore_errors=True)
     kind = jax.devices()[0].device_kind
     ctx = Context(tr, win.steps, cell.config, peaks.PEAKS.get(kind),
-                  win.wall_s)
+                  win.wall_s, cell.workload["chips"])
     metrics = {}
     for m in cell.per_layer:
         v = load_reader(cell.root, m["name"])(ctx)

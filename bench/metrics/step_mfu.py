@@ -1,5 +1,6 @@
-"""step_mfu: percent of the window's wall time that the deadlines'
-required work would take on the chip at its peaks.  Per deadline the
+"""step_mfu: percent of the window's chip time (wall time times the
+cell's chips) that the deadlines' required work would take on one chip
+at its peaks, so a share of the whole host's peak.  Per deadline the
 work is the products the due sensors asked for (one decay per sensor,
 STCF patch sums, counts, the head's operations; each product written
 once) and the scatter of the deadline's events; its least time is the
@@ -25,4 +26,4 @@ def read(ctx):
             nbytes += len(sensors) * w["bytes"]
         nbytes += peaks.scatter_bytes(info.n_events, counts)
         total += peaks.least_time_s(flops, nbytes, ctx.peaks)[0]
-    return 100.0 * total / ctx.window_s
+    return 100.0 * total / (ctx.chips * ctx.window_s)

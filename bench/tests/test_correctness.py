@@ -1,5 +1,6 @@
 """``correct`` at toy sizes: sound runs pass, the bfloat16 control and
-each fault of the timed path that a one-chip cell can have fail."""
+each fault of the timed path that a cell can have fail (on a sharded
+pool also the routing between chips left out)."""
 import pytest
 
 import reference as ref
@@ -21,8 +22,12 @@ def test_sound_run_is_correct_and_control_is_not(root, cell):
     assert not ok, res["control"]
 
 
-@pytest.mark.parametrize("fault", ["unchanged", "half_batch", "altered",
-                                   "unserved"])
-def test_fault_is_caught(root, fault):
-    res = tiny.drive(root, "tiny_qvga", fault=fault)
+ONE_CHIP = ["unchanged", "half_batch", "altered", "unserved"]
+
+
+@pytest.mark.parametrize("cell, fault",
+                         [("tiny_qvga", f) for f in ONE_CHIP]
+                         + [("tiny_qvga_x4", f) for f in ONE_CHIP + ["unrouted"]])
+def test_fault_is_caught(root, cell, fault):
+    res = tiny.drive(root, cell, fault=fault)
     assert not res["correct"], res["checks"]

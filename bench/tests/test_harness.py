@@ -5,6 +5,7 @@ import os
 import subprocess
 import sys
 
+import harness
 import tiny
 from conftest import ROOT
 
@@ -77,9 +78,14 @@ def test_sharded_config_by_name(tmp_path):
                                "why": "test"})
     json.dump(bench, open(os.path.join(root, "BENCHMARK.json"), "w"))
 
-    res = tiny.drive(root, "added_x4", cpu_devices=4)
+    res = tiny.drive(root, "added_x4")
     assert res["correct"], res["checks"]
     assert res["sharded_over"] == 4
+    # the four-chip cell of the benchmark, found by its name
+    cell = harness.load_cell(ROOT, "qvga_tiered_x4")
+    assert cell.config["name"] == "isc-qvga-tiered-x4"
+    assert cell.config["mesh_devices"] == cell.workload["chips"] == 4
+    assert cell.config["n_slots"] == 4 * 64
 
 
 def _run_command(cwd, env_extra=None):
@@ -109,3 +115,34 @@ def test_command_needs_the_program(tmp_path):
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
     out = _run_command(str(tmp_path), {"PYTHONPATH": ""})
     assert out.returncode != 0 and not _has_result(out.stdout)
+
+
+def test_samples_cover_every_tier_and_chip():
+    """The compared products are spread over the tiers and, within each,
+    over the chips: a fault on one chip cannot miss the sample."""
+    samples = [{"k": k, "tier": tier, "chip": (k * 7 + j) % 4, "sensor": j}
+               for k in range(1, 31) for j, tier in enumerate(["g", "t"])]
+    for seed in range(20):
+        got = harness.pick_samples(samples, seed)
+        assert len(got) == harness.SAMPLES
+        assert {(s["tier"], s["chip"]) for s in got} == {
+            (t, c) for t in "gt" for c in range(4)}
+        assert {s["k"] for s in got} >= {30}
+
+
+def test_ingest_batches_warmed_per_chip():
+    """The warmed ingest batches are rows a chip: on one chip as the
+    whole fleet's; spread over four chips, fewer and smaller."""
+    import generator
+
+    config = json.load(open(os.path.join(
+        ROOT, "bench/configs/isc-qvga-tiered.json")))
+    config.update(h=24, w=32, chunk_capacity=16)
+    mix = json.load(open(os.path.join(ROOT, "bench/traffic/tiered_scenes.json")))
+    traffic = generator.generate(mix, config, 5)
+    one = harness.batch_sizes(config, traffic, 24)
+    assert one == harness.batch_sizes(config, traffic, 24, [0] * len(traffic))
+    spread = harness.batch_sizes(config, traffic, 24,
+                                 [i % 4 for i in range(len(traffic))])
+    assert len(one) > 2 and spread == one[:len(spread)]
+    assert len(spread) < len(one)

@@ -62,3 +62,34 @@ def test_a_mix_states_its_scene_seed():
     del m["scene_seed"]
     with pytest.raises(KeyError):
         generator.generate(m, FLEET, 1)
+
+
+def test_crossing_order_is_the_per_pixel_loop():
+    """The vectorised index of each crossing within its pixel equals the
+    loop over pixels of the copied simulator, value and dtype."""
+    kk = np.random.default_rng(7).integers(1, 5, 1000).astype(np.int32)
+    want = np.concatenate([np.arange(c) for c in kk])
+    got = generator.crossing_order(kk)
+    assert got.dtype == want.dtype and np.array_equal(got, want)
+
+
+def test_fleet_copies_give_every_chip_one_copy():
+    """``fleet_copies`` connects the fleet as equal copies one after
+    another: the four-chip configuration's 64 slots a chip each hold
+    one copy of the one-chip fleet, 21 gesture then 43 telemetry."""
+    def config(name):
+        return json.load(open(os.path.join(BENCH, "configs", name + ".json")))
+
+    one = generator.fleet(config("isc-qvga-tiered"))
+    assert one == ([("gesture", i) for i in range(21)]
+                   + [("telemetry", i) for i in range(43)])
+    x4 = generator.fleet(config("isc-qvga-tiered-x4"))
+    assert sorted(x4) == sorted(set(x4)) and len(x4) == 256
+    for chip in range(4):
+        block = x4[64 * chip:64 * (chip + 1)]
+        assert [t for t, _ in block] == [t for t, _ in one]
+        assert [i for _, i in block] == ([21 * chip + i for i in range(21)]
+                                         + [43 * chip + i for i in range(43)])
+    odd = dict(FLEET, fleet_copies=3)
+    with pytest.raises(ValueError):
+        generator.fleet(odd)

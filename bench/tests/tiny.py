@@ -4,7 +4,9 @@
 ``dst``, links the program's ``src/``, and adds a tiny copy of each
 configuration (24x32 sensors, a few of each tier, an 8-slot pool, a
 narrow head) with one cell each, keeping the real configuration's
-limits.  Nothing of the original checkout is changed.
+limits, its ``mesh_devices``, on as many emulated CPU devices, and its
+``fleet_copies``.
+Nothing of the original checkout is changed.
 """
 import json
 import os
@@ -14,7 +16,8 @@ import sys
 
 from conftest import ROOT
 
-CELLS = {"tiny_qvga": ("isc-qvga-tiered", "tiered_scenes")}
+CELLS = {"tiny_qvga": ("isc-qvga-tiered", "tiered_scenes"),
+         "tiny_qvga_x4": ("isc-qvga-tiered-x4", "tiered_scenes")}
 
 
 def make_root(dst: str) -> str:
@@ -26,8 +29,11 @@ def make_root(dst: str) -> str:
     for cell, (name, traffic) in CELLS.items():
         c = json.load(open(os.path.join(dst, "bench/configs", name + ".json")))
         c.update(name="tiny-" + name, h=24, w=32, n_slots=8, connect_wave=4)
+        copies = c.get("fleet_copies", 1)
         for t in c["tiers"]:
-            t["sensors"] = 3 if t["tier"] == "gesture" else 5
+            # 3 + 5 sensors as one copy, 1 + 1 per copy of several
+            t["sensors"] = copies * max(
+                1, (3 if t["tier"] == "gesture" else 5) // copies)
             for p in t["products"].values():
                 if p["kind"] == "classify":
                     p["width"] = 8
@@ -36,7 +42,8 @@ def make_root(dst: str) -> str:
         bench["configs"].append({"name": c["name"], "source": "toy size",
                                  "file": path, "reduced": [], "why": "tests"})
         bench["workloads"].append({"name": cell, "config": c["name"],
-                                   "traffic": traffic, "chips": 1,
+                                   "traffic": traffic,
+                                   "chips": c.get("mesh_devices", 1),
                                    "why": "tests"})
     json.dump(bench, open(os.path.join(dst, "BENCHMARK.json"), "w"))
     return dst
@@ -59,11 +66,13 @@ print(json.dumps(res, default=float))
 """
 
 
-def drive(root: str, cell: str, trace: bool = False, fault: str = "none",
-          cpu_devices: int = 1):
+def drive(root: str, cell: str, trace: bool = False, fault: str = "none"):
     """Run ``harness.run_cell`` of the checkout at ``root`` in a fresh
-    process on ``cpu_devices`` CPU devices (Pallas interpreter), skipping
-    the look for a chip; returns its result."""
+    process on as many CPU devices as the cell has chips (Pallas
+    interpreter), skipping the look for a chip; returns its result."""
+    bench = json.load(open(os.path.join(root, "BENCHMARK.json")))
+    cpu_devices = next(w["chips"] for w in bench["workloads"]
+                       if w["name"] == cell)
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     if cpu_devices > 1:
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " --xla_force_host_"

@@ -9,7 +9,9 @@ needs no more generation.
 Scene intensity fields and ``dvs_from_intensity`` are a copy of
 ``src/repro/events/synthetic.py`` at commit 589672e (log-intensity
 threshold crossings, v2e-style), so the inputs of the benchmark stay put
-when the program's own generator changes.
+when the program's own generator changes.  The index of each crossing
+within its pixel is computed without a loop over pixels; the events
+are the same, bit for bit.
 
 Mix parameters:
 
@@ -125,6 +127,12 @@ def hotel_bar_scene(h, w, rng):
     return intensity
 
 
+def crossing_order(kk: np.ndarray) -> np.ndarray:
+    """Each crossing's index within its pixel, ``0 .. kk[i] - 1`` for
+    pixel ``i``, pixels in order."""
+    return np.arange(int(kk.sum())) - np.repeat(np.cumsum(kk) - kk, kk)
+
+
 def dvs_from_intensity(intensity, h, w, duration, rng, theta=0.2, fps=1000.0,
                        noise_hz=0.0, eps=1e-3, max_events_per_px_per_step=4):
     """Emit +-theta log-intensity crossings with linear time interpolation,
@@ -146,8 +154,7 @@ def dvs_from_intensity(intensity, h, w, duration, rng, theta=0.2, fps=1000.0,
             kk = k[yy, xx]
             pol = (diff[yy, xx] > 0).astype(np.int32)
             reps = np.repeat(np.arange(len(yy)), kk)
-            order = (np.concatenate([np.arange(c) for c in kk]) if len(kk)
-                     else np.zeros(0, int))
+            order = crossing_order(kk)
             frac = ((order + 1).astype(np.float32)
                     / (kk[reps] + 1).astype(np.float32))
             tss.append((t1 - dt) + frac * dt)
@@ -226,10 +233,21 @@ def _rng(seed: int, *key: int) -> np.random.Generator:
 
 
 def fleet(config: dict):
-    """``[(tier, index_in_tier)]`` in connection order (tier blocks, in
-    the configuration's order)."""
-    return [(tier["tier"], i) for tier in config["tiers"]
-            for i in range(tier["sensors"])]
+    """``[(tier, index_in_tier)]`` in connection order: the
+    configuration's ``fleet_copies`` (default 1) equal copies of the
+    fleet one after another, each holding its share of every tier in
+    tier blocks, in the configuration's order.  A pool of
+    ``mesh_devices`` = ``fleet_copies`` chips whose ``n_slots`` the
+    fleet fills then holds one copy on each chip."""
+    copies = config.get("fleet_copies", 1)
+    share = {}
+    for tier in config["tiers"]:
+        if tier["sensors"] % copies:
+            raise ValueError(f"tier {tier['tier']!r}: {tier['sensors']} "
+                             f"sensors do not split into {copies} copies")
+        share[tier["tier"]] = tier["sensors"] // copies
+    return [(tier["tier"], c * share[tier["tier"]] + i) for c in range(copies)
+            for tier in config["tiers"] for i in range(share[tier["tier"]])]
 
 
 def _below(t: np.ndarray, segment_s: float) -> np.ndarray:
